@@ -30,6 +30,7 @@ from repro.core.folding import FoldedTable
 from repro.core.hlo_analysis import analyze_module
 from repro.core.views import api_view, component_view
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.runtime.trainer import init_train_state, make_train_step
 
@@ -222,7 +223,7 @@ def routerbug():
 def gatherbug():
     from repro.core.hlo_flows import find_redundant_gathers
     dev = jax.devices()[0]
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     w = jnp.zeros((256, 256))
     x = jnp.zeros((8, 256))

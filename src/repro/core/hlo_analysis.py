@@ -62,12 +62,8 @@ _BYTES_OPS = {
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """compiled.cost_analysis() with the cross-version shape normalized:
-    jax <= 0.4.x returns [dict] (one per program), newer jax returns dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """compiled.cost_analysis(), or {} where the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def _shape_info(type_str: str) -> Tuple[int, List[List[int]]]:

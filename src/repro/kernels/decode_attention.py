@@ -23,6 +23,7 @@ pos[b] + T, so a slot resuming at depth 40 never streams its neighbour's
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -30,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
 
 NEG_INF = -1e30
 LANES = 128
@@ -48,7 +48,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    kv_len = len_ref[0]  # [1]-blocked per batch row (SMEM scalar)
+    kv_len = len_ref[pl.program_id(0)]  # scalar-prefetched row length
 
     # per-row early exit: this row is done once ik*block_k passes ITS
     # length — other rows of the same call keep streaming their blocks
@@ -95,9 +95,9 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kv_len: [B] int32 PER-ROW valid lengths (None = full S).  Under
     continuous batching every serving slot decodes at its own depth, so
     rows of one call carry arbitrary mixed lengths: the kernel reads each
-    row's length from SMEM, skips whole KV blocks past it (`pl.when` on
-    the arbitrary grid dim — a row at depth 100 does not pay for a
-    neighbour at 32k), and masks the partial block with a per-column
+    row's length from SMEM (scalar prefetch), skips whole KV blocks past
+    it (`pl.when` on the arbitrary grid dim — a row at depth 100 does not
+    pay for a neighbour at 32k), and masks the partial block with a per-column
     iota compare.  A fully-masked row (kv_len == 0, e.g. an empty pool
     slot) short-circuits every block; the l == 0 guard in _finalize
     yields zeros instead of 0/0 NaNs.  return_residuals=True additionally
@@ -125,34 +125,38 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         jax.ShapeDtypeStruct((B, Hkv, g, LANES), jnp.float32),
         jax.ShapeDtypeStruct((B, Hkv, g, LANES), jnp.float32),
     ]
-    out_specs = [
-        pl.BlockSpec((1, 1, g, D), lambda b, h, ik: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, g, LANES), lambda b, h, ik: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, g, LANES), lambda b, h, ik: (b, h, 0, 0)),
-    ]
-
-    o, m, l = pl.pallas_call(
-        kernel,
+    # per-row lengths ride as a scalar-prefetch operand (resident in SMEM
+    # before the body runs), exactly as the paged kernels pass theirs
+    row = lambda b, h, ik, len_ref: (b, h, 0, 0)
+    kv_block = lambda b, h, ik, len_ref: (b, h, ik, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hkv, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ik: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, g, D), row),
+            pl.BlockSpec((1, 1, block_k, D), kv_block),
+            pl.BlockSpec((1, 1, block_k, D), kv_block),
         ],
-        out_specs=out_specs,
-        out_shape=out_shapes,
+        out_specs=[
+            pl.BlockSpec((1, 1, g, D), row),
+            pl.BlockSpec((1, 1, g, LANES), row),
+            pl.BlockSpec((1, 1, g, LANES), row),
+        ],
         scratch_shapes=[
             pltpu.VMEM((g, D), jnp.float32),
             pltpu.VMEM((g, LANES), jnp.float32),
             pltpu.VMEM((g, LANES), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    o, m, l = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shapes,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="xfa_decode_attention",
-    )(kv_len, qg, k, v)
+    )(jnp.asarray(kv_len, jnp.int32), qg, k, v)
 
     o = o.reshape(B, Hq, D)
     if return_residuals:
@@ -160,15 +164,30 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return o
 
 
+def _row_block(rows: int, target: int = 512) -> int:
+    """Query rows per grid step of the chunk kernels: all of them up to
+    `target`, else the largest power-of-two divisor up to it.  The
+    scratch and the [rows, kv block] scores stay inside scoped VMEM for
+    any chunk width (a whole 512-token chunk of 8 q heads per kv head
+    is 4096 rows, 17.6 MiB of scoped VMEM against a 16 MiB limit)."""
+    if rows <= target:
+        return rows
+    br = math.gcd(rows, target)
+    return br if br % 8 == 0 else rows      # (8, 128) tiling or one block
+
+
 def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
                   *, sm_scale: float, block_k: int, num_kv_blocks: int,
-                  chunk: int):
-    """Offset-causal flash over the cache for one (batch, kv-head) pair.
+                  chunk: int, block_rows: int):
+    """Offset-causal flash over the cache for one (batch, kv-head, row
+    block) triple.
 
-    q block is [G*T, D] — all q heads of the kv head × the whole chunk —
-    laid out (g, t) row-major so row r's query index is r % T; its column
-    limit is pos + r % T (the row's own absolute position)."""
-    ik = pl.program_id(2)
+    The kv head's query rows are [G*T, D] — all q heads of the kv head ×
+    the whole chunk — laid out (g, t) row-major so row r's query index is
+    r % T; its column limit is pos + r % T (the row's own absolute
+    position).  The grid walks them block_rows at a time."""
+    row0 = pl.program_id(2) * block_rows
+    ik = pl.program_id(3)
 
     @pl.when(ik == 0)
     def _init():
@@ -176,17 +195,17 @@ def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    pos = pos_ref[0]  # [1]-blocked per batch row (SMEM scalar)
+    pos = pos_ref[pl.program_id(0)]  # scalar-prefetched row offset
 
     # per-row early exit: no query of this chunk reaches past pos + T - 1
     @pl.when(ik * block_k < pos + chunk)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale       # [G*T, D]
+        q = q_ref[0, 0].astype(jnp.float32) * sm_scale       # [BR, D]
         k = k_ref[0, 0].astype(jnp.float32)                  # [BK, D]
         v = v_ref[0, 0].astype(jnp.float32)                  # [BK, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols <= pos + rows % chunk, s, NEG_INF)
 
@@ -311,7 +330,7 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="xfa_decode_attention_paged",
@@ -323,12 +342,14 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
 def _chunk_paged_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                         acc_ref, m_ref, l_ref, *,
                         sm_scale: float, page_size: int, num_pages: int,
-                        chunk: int):
+                        chunk: int, block_rows: int):
     """Paged offset-causal chunk body (see _chunk_kernel): q rows are
-    (g, t) row-major, column limit pos + r % chunk; the KV grid walks
-    block-table slots with the page id prefetched into the BlockSpec."""
+    (g, t) row-major in blocks of block_rows, column limit
+    pos + r % chunk; the KV grid walks block-table slots with the page id
+    prefetched into the BlockSpec."""
     b = pl.program_id(0)
-    ik = pl.program_id(2)
+    row0 = pl.program_id(2) * block_rows
+    ik = pl.program_id(3)
 
     @pl.when(ik == 0)
     def _init():
@@ -341,12 +362,12 @@ def _chunk_paged_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     # per-row early exit: no query of this chunk reaches past pos + T - 1
     @pl.when(ik * page_size < pos + chunk)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale       # [G*T, D]
+        q = q_ref[0, 0].astype(jnp.float32) * sm_scale       # [BR, D]
         k = k_ref[0, 0].astype(jnp.float32)                  # [PS, D]
         v = v_ref[0, 0].astype(jnp.float32)                  # [PS, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = ik * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(cols <= pos + rows % chunk, s, NEG_INF)
@@ -392,38 +413,35 @@ def chunk_attention_paged(q: jax.Array, k_pages: jax.Array,
     scale = sm_scale if sm_scale is not None else D ** -0.5
 
     qg = q.reshape(B, Hkv, g, T, D).reshape(B, Hkv, g * T, D)
+    br = _row_block(g * T)
     kernel = functools.partial(
         _chunk_paged_kernel, sm_scale=scale, page_size=ps, num_pages=NB,
-        chunk=T)
+        chunk=T, block_rows=br)
 
+    rows = lambda b, h, iq, ik, pos_ref, bt_ref: (b, h, iq, 0)
+    page = lambda b, h, iq, ik, pos_ref, bt_ref: (bt_ref[b, ik], h, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, NB),
+        grid=(B, Hkv, g * T // br, NB),
         in_specs=[
-            pl.BlockSpec((1, 1, g * T, D),
-                         lambda b, h, ik, pos_ref, bt_ref: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, ps, D),
-                         lambda b, h, ik, pos_ref, bt_ref:
-                         (bt_ref[b, ik], h, 0, 0)),
-            pl.BlockSpec((1, 1, ps, D),
-                         lambda b, h, ik, pos_ref, bt_ref:
-                         (bt_ref[b, ik], h, 0, 0)),
+            pl.BlockSpec((1, 1, br, D), rows),
+            pl.BlockSpec((1, 1, ps, D), page),
+            pl.BlockSpec((1, 1, ps, D), page),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, g * T, D),
-            lambda b, h, ik, pos_ref, bt_ref: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, br, D), rows),
         scratch_shapes=[
-            pltpu.VMEM((g * T, D), jnp.float32),
-            pltpu.VMEM((g * T, LANES), jnp.float32),
-            pltpu.VMEM((g * T, LANES), jnp.float32),
+            pltpu.VMEM((br, D), jnp.float32),
+            pltpu.VMEM((br, LANES), jnp.float32),
+            pltpu.VMEM((br, LANES), jnp.float32),
         ],
     )
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g * T, D), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
         name="xfa_chunk_attention_paged",
     )(jnp.asarray(pos, jnp.int32), jnp.asarray(block_table, jnp.int32),
@@ -455,29 +473,36 @@ def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # group q heads by kv head and flatten (g, T) into kernel rows
     qg = q.reshape(B, Hkv, g, T, D).reshape(B, Hkv, g * T, D)
 
+    br = _row_block(g * T)
     kernel = functools.partial(
         _chunk_kernel, sm_scale=scale, block_k=block_k, num_kv_blocks=nk,
-        chunk=T)
+        chunk=T, block_rows=br)
 
+    # per-row offsets ride as a scalar-prefetch operand (see decode)
+    rows = lambda b, h, iq, ik, pos_ref: (b, h, iq, 0)
+    kv_block = lambda b, h, iq, ik, pos_ref: (b, h, ik, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, Hkv, g * T // br, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, br, D), rows),
+            pl.BlockSpec((1, 1, block_k, D), kv_block),
+            pl.BlockSpec((1, 1, block_k, D), kv_block),
+        ],
+        out_specs=pl.BlockSpec((1, 1, br, D), rows),
+        scratch_shapes=[
+            pltpu.VMEM((br, D), jnp.float32),
+            pltpu.VMEM((br, LANES), jnp.float32),
+            pltpu.VMEM((br, LANES), jnp.float32),
+        ],
+    )
     o = pl.pallas_call(
         kernel,
-        grid=(B, Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ik: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g * T, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik: (b, h, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g * T, D), lambda b, h, ik: (b, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g * T, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g * T, D), jnp.float32),
-            pltpu.VMEM((g * T, LANES), jnp.float32),
-            pltpu.VMEM((g * T, LANES), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
         name="xfa_chunk_attention",
     )(pos, qg, k, v)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 
 from repro.core.device_fold import annotate_cost
 from repro.core import tracer as xfa
+from repro.parallel.axes import (axis_size, dims_spec, get_runtime_mesh,
+                                 in_manual_region)
 
 from . import decode_attention as _dec
 from . import flash_attention as _fa
@@ -40,6 +42,21 @@ def _resolve(impl: str) -> str:
 
 def _bytes(*arrs) -> float:
     return float(sum(a.size * a.dtype.itemsize for a in arrs))
+
+
+def _per_device(kernel, args, dims, n_out: int = 1):
+    """Run a Mosaic kernel under the ambient mesh.  XLA cannot partition
+    a Mosaic kernel, so each device runs it on its own block through
+    shard_map: args are split by `dims` ({dim: logical axis} per arg,
+    see parallel.axes.dims_spec) and every output has the layout of the
+    first arg.  Without a mesh, or inside a shard_map body already, the
+    kernel is called as is."""
+    if get_runtime_mesh() is None or in_manual_region():
+        return kernel(*args)
+    specs = tuple(dims_spec(a.shape, d) for a, d in zip(args, dims))
+    out_specs = specs[0] if n_out == 1 else (specs[0],) * n_out
+    return jax.shard_map(kernel, mesh=get_runtime_mesh(), in_specs=specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def attention(q, k, v, *, causal: bool = True,
@@ -64,8 +81,15 @@ def attention(q, k, v, *, causal: bool = True,
                                      logit_softcap=logit_softcap,
                                      q_offset=Sk - Sq if causal else 0)
     itp = (not _on_tpu()) if interpret is None else interpret
-    return _fa.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+    kernel = functools.partial(_fa.flash_attention, causal=causal,
+                               sm_scale=sm_scale,
                                logit_softcap=logit_softcap, interpret=itp)
+    # heads split over 'model' only where q AND kv heads both divide it,
+    # so each device keeps whole GQA groups
+    m = axis_size("model")
+    dims = {0: "batch", 1: "model"} \
+        if q.shape[1] % m == 0 and k.shape[1] % m == 0 else {0: "batch"}
+    return _per_device(kernel, (q, k, v), (dims, dims, dims))
 
 
 def decode_attention(q, k, v, *, kv_len=None, sm_scale=None,
@@ -181,7 +205,8 @@ def rmsnorm(x, w, *, eps: float = 1e-5, impl: str = "auto",
     if mode in ("ref", "chunked"):
         return ref.rmsnorm(x, w, eps=eps)
     itp = (not _on_tpu()) if interpret is None else interpret
-    return _rms.rmsnorm(x, w, eps=eps, interpret=itp)
+    kernel = functools.partial(_rms.rmsnorm, eps=eps, interpret=itp)
+    return _per_device(kernel, (x, w), ({0: "batch"}, {}))
 
 
 def rmsnorm_add(x, residual, w, *, eps: float = 1e-5, impl: str = "auto",
@@ -193,7 +218,9 @@ def rmsnorm_add(x, residual, w, *, eps: float = 1e-5, impl: str = "auto",
         s = x + residual
         return ref.rmsnorm(s, w, eps=eps), s
     itp = (not _on_tpu()) if interpret is None else interpret
-    return _rms.rmsnorm_add(x, residual, w, eps=eps, interpret=itp)
+    kernel = functools.partial(_rms.rmsnorm_add, eps=eps, interpret=itp)
+    return _per_device(kernel, (x, residual, w),
+                       ({0: "batch"}, {0: "batch"}, {}), n_out=2)
 
 
 def ssd_scan(x, dt, a, b, c, *, chunk: int = 128, h0=None,

@@ -173,14 +173,27 @@ def _flash_chunked_fwd(q, k, v, causal, sm_scale, block_k, q_offset):
 
 
 def _flash_chunked_bwd(causal, sm_scale, block_k, q_offset, res, do):
+    return flash_attention_bwd(res, do, causal=causal, sm_scale=sm_scale,
+                               block_k=block_k, q_offset=q_offset)
+
+
+def flash_attention_bwd(res, do, *, causal: bool, sm_scale: float,
+                        block_k: int, q_offset: int = 0,
+                        logit_softcap: float = 0.0):
+    """FlashAttention-2 backward in blockwise jnp: res = (q, k, v, o, lse)
+    with lse [B, Hq, Sq] the forward's log-sum-exp; p is recomputed one
+    kv block at a time, so no [Sq, Sk] matrix is ever live.  Shared by
+    `_flash_chunked` and the Pallas flash kernel's custom_vjp.  block_k
+    must divide Sk.  Returns (dq, dk, dv)."""
     # custom_vjp bwd is traced OUTSIDE the model's named_scope — re-enter it
     # so the XFA static layer attributes these loops to the kernel scope
     with jax.named_scope("attention"):
-        return _flash_chunked_bwd_impl(causal, sm_scale, block_k, q_offset,
-                                       res, do)
+        return _flash_bwd_impl(causal, sm_scale, block_k, q_offset,
+                               logit_softcap, res, do)
 
 
-def _flash_chunked_bwd_impl(causal, sm_scale, block_k, q_offset, res, do):
+def _flash_bwd_impl(causal, sm_scale, block_k, q_offset, logit_softcap,
+                    res, do):
     from repro.parallel.axes import shard_dims  # local: avoid import cycle
     q, k, v, o, lse = res
     B, Hq, Sq, D = q.shape
@@ -206,6 +219,9 @@ def _flash_chunked_bwd_impl(causal, sm_scale, block_k, q_offset, res, do):
         kk, vv = _c(kk), _c(vv)
         kf, vf = kk.astype(jnp.float32), vv.astype(jnp.float32)
         s = jnp.einsum("bhqd,bhkd->bhqk", qs, kf)
+        if logit_softcap > 0:
+            t = jnp.tanh(s / logit_softcap)
+            s = logit_softcap * t
         p = jnp.exp(s - lse_r[..., None])                # softmax via lse
         if causal:
             cols = ik * bk + jnp.arange(bk)
@@ -214,6 +230,8 @@ def _flash_chunked_bwd_impl(causal, sm_scale, block_k, q_offset, res, do):
         dv = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
         dp = jnp.einsum("bhqd,bhkd->bhqk", dof, vf)
         ds = p * (dp - delta[..., None])
+        if logit_softcap > 0:
+            ds = ds * (1.0 - t * t)                      # d softcap / ds
         dq_acc = _c(dq_acc + jnp.einsum("bhqk,bhkd->bhqd", ds, kf))
         dk = _c(jnp.einsum("bhqk,bhqd->bhkd", ds, qs))
         return dq_acc, (dk, dv)

@@ -1,11 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 production mesh and extract the roofline terms from the compiled artifact.
 
-The two lines above run BEFORE any other import (jax locks the device count
-at first init). Do NOT import this module from tests — run it as
+The lines above run BEFORE any other import (jax locks the device count
+at first init).  The dry-run runs on 512 virtual CPU devices and pins the
+CPU platform, so on a host with a chip neither it nor the children
+`dryrun_all.py` starts ever takes the chip.  Do NOT import this module
+from tests — run it as
 `python -m repro.launch.dryrun --arch <id> --shape <name> [--multi-pod]`.
 
 Per cell, the dry-run records to artifacts/dryrun/<cell>.json:

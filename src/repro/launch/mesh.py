@@ -7,17 +7,20 @@ from __future__ import annotations
 from typing import Dict, Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    return jax.make_mesh(shape, axes)
+    """The one place meshes are built.  Axes are `Auto`: the model code
+    places values with `with_sharding_constraint` (parallel/axes.py),
+    which only accepts Auto axes — `jax.make_mesh` defaults to Explicit."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:

@@ -12,12 +12,16 @@ and TTFT are only meaningful here).
 
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama_1_1b \
         --smoke --mode open --rate 4 --requests 32
+
+Exits non-zero when the engine failed, or when any request errored or
+did not finish.
 """
 
 from __future__ import annotations
 
 import argparse
 import time
+from typing import List, Optional
 
 import jax
 import numpy as np
@@ -46,7 +50,7 @@ def summarize(done, wall_s: float) -> str:
     return "\n".join(lines)
 
 
-def main() -> int:
+def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -54,6 +58,11 @@ def main() -> int:
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=256)
     ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument("--min-prompt", type=int, default=4,
+                    help="shortest prompt drawn, tokens")
+    ap.add_argument("--max-prompt", type=int, default=0,
+                    help="prompts are drawn below this length (0: "
+                         "max_seq // 4)")
     ap.add_argument("--ckpt", default="")
     # -- workload ------------------------------------------------------------
     ap.add_argument("--mode", choices=("closed", "open"), default="closed",
@@ -131,8 +140,10 @@ def main() -> int:
                          "time (0: governor off, every boundary fully "
                          "timed); hot edges back off to 1-in-k timing "
                          "with unbiased scale-up, counting stays exact")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.xfa_host_label:
         from repro.profile import set_host_label
         set_host_label(args.xfa_host_label)
@@ -172,13 +183,19 @@ def main() -> int:
         xfa_overhead_budget=args.xfa_budget_pct / 100.0))
     # sampling knobs ride in ServeConfig: submit() defaults to them
     rng = np.random.default_rng(0)
+    hi = args.max_prompt or args.max_seq // 4
     prompts = [rng.integers(0, cfg.vocab,
-                            int(rng.integers(4, args.max_seq // 4)))
+                            int(rng.integers(args.min_prompt, hi)))
                for _ in range(args.requests)]
     t0 = time.monotonic()
     done = run_workload(engine, prompts, args.max_new, mode=args.mode,
                         rate=args.rate, rng=rng)
     print(summarize(done, time.monotonic() - t0))
+    errored = [r.uid for r in done if r.error is not None or not r.done]
+    if engine.error is not None or errored or len(done) < len(prompts):
+        print(f"FAILED: engine error {engine.error!r}; errored requests "
+              f"{errored}; {len(done)} of {len(prompts)} finished")
+        return 1
     return 0
 
 

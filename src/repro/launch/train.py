@@ -4,12 +4,17 @@
         --smoke --steps 50 [--mesh 4x2] [--resume]
 
 On a real pod: omit --smoke, pass --mesh 16x16 (the process count must
-match); this box runs the same code path on the smoke configs.
+match); this box runs the same code path on the smoke configs.  --layers
+keeps a config's published widths and cuts its depth to what one chip
+holds.  Exits non-zero when the final loss is not finite.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import math
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
@@ -18,15 +23,19 @@ from repro.configs import get_config, get_smoke
 from repro.configs.base import TrainConfig
 from repro.core.session import XFASession
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.parallel.axes import runtime_mesh
 from repro.runtime.trainer import Trainer
 
 
-def main() -> int:
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the config to this many layers, widths "
+                         "unchanged (0: the config's own depth)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -67,17 +76,24 @@ def main() -> int:
                          "time (0: governor off, every boundary fully "
                          "timed); hot edges back off to 1-in-k timing "
                          "with unbiased scale-up, counting stays exact")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def train(args: argparse.Namespace) -> Tuple[Trainer, Dict[str, Any],
+                                               Dict[str, float]]:
+    """Build and run the trainer; returns (trainer, final state, metrics
+    of the last step)."""
     if args.xfa_host_label:
         from repro.profile import set_host_label
         set_host_label(args.xfa_host_label)
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     mesh = None
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split("x"))
         axes = {2: ("data", "model"), 3: ("pod", "data", "model")}[len(shape)]
-        mesh = jax.make_mesh(shape, axes)
+        mesh = make_mesh(shape, axes)
 
     model = build_model(cfg, impl="auto")
     tcfg = TrainConfig(total_steps=args.steps, learning_rate=args.lr,
@@ -101,8 +117,19 @@ def main() -> int:
     with runtime_mesh(mesh):
         state, metrics = trainer.run(jax.random.key(0), data, args.steps,
                                      resume=args.resume)
+    return trainer, state, metrics
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    trainer, _, metrics = train(parse_args(argv))
     print(f"done: {metrics}")
     print(trainer.session.report().render(components=("app",)))
+    loss = metrics.get("loss")          # None: no step left to run
+    if loss is not None and not math.isfinite(loss):
+        print(f"FAILED: final loss {loss} is not finite")
+        return 1
     return 0
 
 

@@ -32,7 +32,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.core.device_fold import DeviceFoldSpec, annotate_cost
 from repro.parallel.axes import axis_size, get_runtime_mesh, shard
-from repro.parallel.compat import shard_map
+from jax import shard_map
 
 from .layers import Params, Runtime, _init, linear, pdtype
 

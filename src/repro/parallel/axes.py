@@ -86,9 +86,10 @@ def resolve_spec(*logical_axes: Optional[str]) -> P:
 
 
 def shard(x, *logical_axes: Optional[str]):
-    """with_sharding_constraint against the ambient mesh; no-op without one."""
+    """with_sharding_constraint against the ambient mesh; no-op without one
+    and inside a shard_map body (values there are already per device)."""
     mesh = get_runtime_mesh()
-    if mesh is None:
+    if mesh is None or in_manual_region():
         return x
     spec = resolve_spec(*logical_axes)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
@@ -104,11 +105,20 @@ def shard_dims(x, dim_axes: Dict[int, str]):
     operands EVERY iteration (measured: a 16 GB all-gather per kv-block on
     deepseek MLA train — EXPERIMENTS.md §Perf)."""
     mesh = get_runtime_mesh()
-    if mesh is None:
+    if mesh is None or in_manual_region():
         return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, dims_spec(x.shape, dim_axes)))
+
+
+def dims_spec(shape: Tuple[int, ...], dim_axes: Dict[int, str]) -> P:
+    """The PartitionSpec `shard_dims` applies: each listed dim gets its
+    logical axis's mesh axes where the dim size divides their extent,
+    and stays replicated otherwise.  Requires an installed mesh."""
+    mesh = get_runtime_mesh()
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     rules = get_rules()
-    parts: list = [None] * x.ndim
+    parts: list = [None] * len(shape)
     used: set = set()
     for dim, logical in dim_axes.items():
         mapped = tuple(m for m in rules.get(logical, (logical,))
@@ -116,11 +126,15 @@ def shard_dims(x, dim_axes: Dict[int, str]):
         extent = 1
         for m in mapped:
             extent *= sizes[m]
-        if mapped and extent > 1 and x.shape[dim] % extent == 0:
+        if mapped and extent > 1 and shape[dim] % extent == 0:
             parts[dim] = mapped[0] if len(mapped) == 1 else mapped
             used.update(mapped)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*parts)))
+    return P(*parts)
+
+
+def in_manual_region() -> bool:
+    """True while tracing a shard_map body, where values are per-device."""
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def named_sharding(*logical_axes: Optional[str]) -> Optional[NamedSharding]:

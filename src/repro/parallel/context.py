@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ops, ref
-from repro.parallel.compat import shard_map
+from jax import shard_map
 
 
 def _local_split_k(q, k_loc, v_loc, pos, *, axis: str, seq_shards: int,

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
+from jax import shard_map
 
 
 def gpipe_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],

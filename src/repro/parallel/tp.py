@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.parallel.axes import get_rules, get_runtime_mesh
-from repro.parallel.compat import shard_map
+from jax import shard_map
 
 
 def _axes(mesh: Mesh) -> Tuple[Tuple[str, ...], Optional[str]]:

@@ -821,6 +821,11 @@ class ServingEngine:
                 req._done_event.set()
             self._stop = True
 
+    @property
+    def error(self) -> Optional[BaseException]:
+        """The failure that stopped the serve loop, if any."""
+        return self._error
+
     # -- profiling ----------------------------------------------------------
     def write_profile_shard(self) -> None:
         """Refresh this replica's profile shard (host tracer folds)."""

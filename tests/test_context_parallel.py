@@ -14,6 +14,7 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.kernels import ref
+    from repro.launch.mesh import make_mesh
     from repro.parallel.context import context_parallel_decode
 
     rng = np.random.default_rng(0)
@@ -21,7 +22,7 @@ SCRIPT = textwrap.dedent("""
     q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
 
     for pos in (S - 1, 100, 63):
         want = ref.decode_attention(

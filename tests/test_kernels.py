@@ -77,6 +77,32 @@ def test_attention_softcap():
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("shape,causal,softcap", [
+    ((1, 2, 1, 128, 128, 32), True, 0.0),       # GQA, one q block
+    ((1, 4, 2, 256, 256, 32), True, 0.0),       # several q and kv blocks
+    ((1, 2, 2, 128, 256, 32), False, 0.0),      # Sq < Sk, no mask
+    ((1, 2, 1, 128, 128, 32), True, 30.0),      # logit softcap
+])
+def test_flash_attention_pallas_grad(shape, causal, softcap):
+    """The Pallas kernel's custom_vjp (kernel forward + blockwise jnp
+    backward) against autodiff of the plain reference."""
+    B, Hq, Hkv, Sq, Sk, D = shape
+    q, k, v = arr(B, Hq, Sq, D), arr(B, Hkv, Sk, D), arr(B, Hkv, Sk, D)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+
+    pallas = lambda q, k, v: ops.attention(
+        q, k, v, causal=causal, logit_softcap=softcap, impl="pallas",
+        interpret=True)
+    oracle = lambda q, k, v: ref.attention(
+        q, k, v, causal=causal, logit_softcap=softcap)
+    got = jax.grad(loss(pallas), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-4)
+
+
 # --------------------------------------------------------- decode attention --
 DEC_SHAPES = [(1, 1, 1, 128, 32), (2, 4, 2, 256, 64), (2, 8, 1, 512, 32)]
 
@@ -102,6 +128,7 @@ CHUNK_SHAPES = [
     (1, 1, 1, 4, 128, 32),
     (2, 4, 2, 8, 256, 64),
     (2, 8, 1, 16, 512, 32),    # MQA, multi-block cache
+    (1, 8, 1, 96, 256, 32),    # g*T = 768 rows: three 256-row blocks
 ]
 
 
@@ -195,6 +222,34 @@ def test_rmsnorm_add_pallas():
     y2, s2 = ops.rmsnorm_add(x, r, w, impl="ref")
     np.testing.assert_allclose(y1, y2, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(s1, s2, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rmsnorm_pallas_grad(dtype):
+    x, w = arr(2, 37, 128, dtype=dtype), arr(128, dtype=dtype)
+    g = arr(2, 37, 128, dtype=dtype)
+
+    def vjp(fn):
+        return jax.vjp(fn, x, w)[1](g)
+
+    got = vjp(lambda x, w: ops.rmsnorm(x, w, impl="pallas", interpret=True))
+    want = vjp(lambda x, w: ref.rmsnorm(x, w))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=5 * tol(dtype), rtol=5 * tol(dtype))
+
+
+def test_rmsnorm_add_pallas_grad():
+    x, r, w = arr(64, 128), arr(64, 128), arr(128)
+    gy, gs = arr(64, 128), arr(64, 128)
+
+    def vjp(impl, **kw):
+        fn = lambda x, r, w: ops.rmsnorm_add(x, r, w, impl=impl, **kw)
+        return jax.vjp(fn, x, r, w)[1]((gy, gs))
+
+    for a, b in zip(vjp("pallas", interpret=True), vjp("ref")):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-4)
 
 
 # ---------------------------------------------------------------- ssd scan --

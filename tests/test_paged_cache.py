@@ -198,6 +198,24 @@ class TestPagedAttentionKernels:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5)
 
+    def test_pallas_chunk_paged_row_blocks(self):
+        """128 q heads of one kv head x 6 tokens = 768 query rows, walked
+        in three 256-row blocks whose starts are not multiples of T."""
+        rng = np.random.default_rng(4)
+        kv = [jnp.asarray(rng.normal(size=(self.B, 1, self.S, self.D)),
+                          jnp.float32) for _ in range(2)]
+        kp, bt = scatter_pages(rng, kv[0], self.PS, 1 + 2 * self.B * self.NB)
+        vp, _ = scatter_pages(rng, kv[1], self.PS, 1 + 2 * self.B * self.NB,
+                              bt=bt)
+        q = jnp.asarray(rng.normal(size=(self.B, 128, 6, self.D)),
+                        jnp.float32)
+        pos = jnp.asarray([0, 11, 25], jnp.int32)
+        want = ref.chunk_attention_paged(q, kp, vp, block_table=bt, pos=pos)
+        got = ops.chunk_attention_paged(q, kp, vp, block_table=bt, pos=pos,
+                                        impl="pallas", interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5)
+
     def test_pallas_decode_paged_interpret(self):
         rng, _, _, kp, vp, bt = self._fixture(4)
         q = jnp.asarray(rng.normal(size=(self.B, self.Hq, self.D)),

@@ -12,10 +12,11 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.parallel.pipeline import (bubble_fraction, gpipe_apply,
                                          split_stages)
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     S, M, B, D = 4, 6, 2, 16
     rng = np.random.default_rng(0)
     # 8 layers -> 4 stages x 2 layers; each layer: x -> tanh(x @ w)

@@ -16,10 +16,11 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np, dataclasses
     from repro.configs import get_smoke
     from repro.models import build_model
+    from repro.launch.mesh import make_mesh
     from repro.parallel.axes import runtime_mesh
     from repro.core.hlo_analysis import analyze_module
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = dataclasses.replace(get_smoke("tinyllama_1_1b"), d_ff=256)
     tok = jax.random.randint(jax.random.key(1), (4, 32), 0, cfg.vocab)
     batch = {"tokens": tok, "labels": tok,
