@@ -28,6 +28,13 @@ TPU/JAX adaptation of the mechanisms:
 Wait separation (Scaler §3.5): boundaries tagged kind='wait' (blocking joins,
 queue gets, device sync) fold into a separate Wait category so views can
 report not-useful time distinctly.
+
+Profiler bridge: `profiler_spans(True)` makes every bracketed boundary
+(@api, @wait, wrap, scope; timed, counting-only and sampled-out alike)
+also open a `jax.profiler.TraceAnnotation` named `xfa.<component>.<api>`
+for the bracket's duration, so the folded edges appear as spans in a
+`jax.profiler` trace, on the host clock the device planes are aligned
+to.  Off (the default), a boundary pays one attribute test for it.
 """
 
 from __future__ import annotations
@@ -75,6 +82,9 @@ class Tracer:
         #: optional adaptive overhead governor (core.sampler); None means
         #: every boundary is timed on every call
         self.sampler = None
+        #: the profiler bridge: jax.profiler.TraceAnnotation while on
+        #: (see profiler_spans), None while off
+        self.annotation = None
         self._stack = _Stack()
 
     # -- caller identity ----------------------------------------------------
@@ -120,40 +130,49 @@ class Tracer:
 
         def deco(fn: Callable) -> Callable:
             api_name = name or fn.__name__
+            span = f"xfa.{component}.{api_name}"
             slot_cache: Dict[str, SlotInfo] = {}
 
             @functools.wraps(fn)
             def wrapper(*args, **kwargs):
                 if not self.enabled:
                     return fn(*args, **kwargs)
-                caller = self.current_component()
-                slot = slot_cache.get(caller)
-                if slot is None:
-                    slot = self.tables.registry.resolve(
-                        caller, component, api_name, kind)
-                    slot_cache[caller] = slot
-                scale = 1
-                if not self.timing:
-                    scale = 0
-                elif self.sampler is not None:
-                    scale = self.sampler.observe(slot.slot)
-                if scale == 0:
-                    # counting-only / sampled-out: exact count, plus a
-                    # lightweight NO-TIMESTAMP frame so nested boundaries
-                    # still fold with the true caller (Relation-Aware
-                    # Data Folding holds in every mode)
-                    self.tables.table().record_count(slot.slot)
-                    frames = self._stack.frames
-                    frames.append(_Frame(component, api_name, 0))
+                ann = self.annotation
+                if ann is not None:
+                    ann = ann(span)
+                    ann.__enter__()
+                try:
+                    caller = self.current_component()
+                    slot = slot_cache.get(caller)
+                    if slot is None:
+                        slot = self.tables.registry.resolve(
+                            caller, component, api_name, kind)
+                        slot_cache[caller] = slot
+                    scale = 1
+                    if not self.timing:
+                        scale = 0
+                    elif self.sampler is not None:
+                        scale = self.sampler.observe(slot.slot)
+                    if scale == 0:
+                        # counting-only / sampled-out: exact count, plus a
+                        # lightweight NO-TIMESTAMP frame so nested
+                        # boundaries still fold with the true caller
+                        # (Relation-Aware Data Folding holds in every mode)
+                        self.tables.table().record_count(slot.slot)
+                        frames = self._stack.frames
+                        frames.append(_Frame(component, api_name, 0))
+                        try:
+                            return fn(*args, **kwargs)
+                        finally:
+                            frames.pop()
+                    frame = self.enter(component, api_name)
                     try:
                         return fn(*args, **kwargs)
                     finally:
-                        frames.pop()
-                frame = self.enter(component, api_name)
-                try:
-                    return fn(*args, **kwargs)
+                        self.exit(frame, slot, scale)
                 finally:
-                    self.exit(frame, slot, scale)
+                    if ann is not None:
+                        ann.__exit__(None, None, None)
 
             wrapper.__xfa__ = (component, api_name, kind)  # type: ignore
             return wrapper
@@ -176,6 +195,10 @@ class Tracer:
         if not self.enabled:
             yield
             return
+        ann = self.annotation
+        if ann is not None:
+            ann = ann(f"xfa.{component}.{api}")
+            ann.__enter__()
         caller = self.current_component()
         slot = self.tables.registry.resolve(caller, component, api, kind)
         frame = self.enter(component, api)
@@ -183,6 +206,8 @@ class Tracer:
             yield
         finally:
             self.exit(frame, slot)
+            if ann is not None:
+                ann.__exit__(None, None, None)
 
     def count_event(self, component: str, api: str, n: int = 1,
                     kind: int = KIND_CALL) -> None:
@@ -236,6 +261,19 @@ class Tracer:
             t.record_count(slot.slot)
             return
         t.record(slot.slot, int(value), 0)
+
+    # -- profiler bridge ------------------------------------------------------
+    def profiler_spans(self, on: bool = True) -> None:
+        """Turn the profiler bridge on or off: while on, every bracketed
+        boundary also opens a `jax.profiler.TraceAnnotation` named
+        `xfa.<component>.<api>` for its duration (a no-op unless a
+        profiler trace is being taken).  jax is imported only here, so
+        the core stays importable without it."""
+        if on:
+            from jax.profiler import TraceAnnotation
+            self.annotation = TraceAnnotation
+        else:
+            self.annotation = None
 
     # -- overhead governor --------------------------------------------------
     def set_overhead_budget(self, budget_fraction: float,
@@ -297,6 +335,10 @@ def set_enabled(on: bool) -> None:
 
 def set_timing(on: bool) -> None:
     TRACER.timing = on
+
+
+def profiler_spans(on: bool = True) -> None:
+    TRACER.profiler_spans(on)
 
 
 def set_overhead_budget(budget_fraction: float, **kwargs):

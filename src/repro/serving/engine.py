@@ -50,12 +50,25 @@ blocks until completion.  `start()` runs the engine on a background
 thread (open-loop serving); without it, `run_until_drained()` drives the
 same loop synchronously (closed-loop benchmarks, tests).
 
-XFA instrumentation ('serve'): prefill_request and decode_tick are
+XFA instrumentation ('serve'): admit (slot binding) and decode_tick are
 traced boundaries, every batched chunk step folds a `prefill_chunk`
 duration, and every batched call folds a `prefill_batch_occupancy`
 gauge (percent of compiled rows that were real slots, not bucket pad) —
 the flow graph separates prefill cost from decode cost per tick and
-shows whether cross-slot batching engages;
+shows whether cross-slot batching engages.  Each tick is split into
+scope edges in the order it runs: `plan` (continuation plan, schedule,
+admissions), per prefill group `prefill_inputs` (host inputs, page
+grants, table slice), `prefill_sync` (Wait: the group's
+block_until_ready) and per completed row `first_token` (its logits
+copy and sample_one), then under decode_tick `decode_inputs` (rebuild,
+grants, uploads), `sample` (Wait: pooled sampler and its host read)
+and `emit` (token callbacks, finishes).  `decode_stall` folds, per
+tick that starts with decoding rows, the time from step() entry to the
+decode dispatch once per such row (the inter-token gap's share spent
+before the decode call); `prefill_residence` folds each request's
+admission to first token; the `decode_pages` / `decode_page_slots`
+gauges are the pages the decode call's rows hold and the block-table
+slots the paged kernel's grid addresses;
 queue_wait (Wait kind), ttft, decode_token and e2e latency phases fold
 via tracer.record_duration (which also folds the bounded latency
 histograms behind the p50/p95/p99 read-out); truncated_prompt is a count
@@ -537,39 +550,43 @@ class ServingEngine:
         slots = self.scheduler.slots
         B = len(idxs)
         Bb = self.scheduler.batch_bucket(B)
-        tokens = np.zeros((Bb, width), np.int32)
-        pos = np.zeros((Bb,), np.int32)
-        valid = np.zeros((Bb,), np.int32)
-        for r, (i, n) in enumerate(zip(idxs, ns)):
-            slot = slots[i]
-            tokens[r, :n] = [slot.pending.popleft() for _ in range(n)]
-            pos[r] = slot.pos
-            valid[r] = n
+        with xfa.scope("serve", "prefill_inputs"):
+            tokens = np.zeros((Bb, width), np.int32)
+            pos = np.zeros((Bb,), np.int32)
+            valid = np.zeros((Bb,), np.int32)
+            for r, (i, n) in enumerate(zip(idxs, ns)):
+                slot = slots[i]
+                tokens[r, :n] = [slot.pending.popleft() for _ in range(n)]
+                pos[r] = slot.pos
+                valid[r] = n
+            if self.paged:
+                # grant the pages this chunk's frontier will cross, then
+                # run the group straight against the shared arena — no
+                # stashes, no scatter: the block table IS the slot's cache
+                # row.  Pad rows carry an all-zero table (writes land on
+                # scratch).
+                for i, n in zip(idxs, ns):
+                    self._grant_rows(i, slots[i].pos + n)
+                bt = np.zeros((Bb, self._n_blocks), np.int32)
+                bt[:B] = self.block_tables[idxs]
+                cache, rows = self.cache, (pos, bt)
+            else:
+                cache = self._gather_stashes(
+                    [slots[i].stash for i in idxs], Bb - B)
+                rows = (pos,)
+            t0 = time.perf_counter_ns()
+            args = (jnp.asarray(tokens), self.table, cache,
+                    *map(jnp.asarray, rows), jnp.asarray(valid))
+        logits, cache, self.table = self._chunk(self.params, *args)
         if self.paged:
-            # grant the pages this chunk's frontier will cross, then run
-            # the group straight against the shared arena — no stashes,
-            # no scatter: the block table IS the slot's cache row.  Pad
-            # rows carry an all-zero table (writes land on scratch).
-            for i, n in zip(idxs, ns):
-                self._grant_rows(i, slots[i].pos + n)
-            bt = np.zeros((Bb, self._n_blocks), np.int32)
-            bt[:B] = self.block_tables[idxs]
-            gathered = None
-            t0 = time.perf_counter_ns()
-            logits, self.cache, self.table = self._chunk(
-                self.params, jnp.asarray(tokens), self.table, self.cache,
-                jnp.asarray(pos), jnp.asarray(bt), jnp.asarray(valid))
+            self.cache = cache
         else:
-            gathered = self._gather_stashes([slots[i].stash for i in idxs],
-                                            Bb - B)
-            t0 = time.perf_counter_ns()
-            logits, gathered, self.table = self._chunk(
-                self.params, jnp.asarray(tokens), self.table, gathered,
-                jnp.asarray(pos), jnp.asarray(valid))
+            gathered = cache
         # sync before the end timestamp: jitted calls return unready
         # arrays, and mid-prompt chunks have no downstream host read to
         # block on — without this the fold times dispatch, not compute
-        jax.block_until_ready(logits)
+        with xfa.scope("serve", "prefill_sync", kind=KIND_WAIT):
+            jax.block_until_ready(logits)
         # its own flow-graph edge: diagnose separates prefill interference
         # from decode cost per tick (wait-dominance / hot-edge detectors)
         xfa.record_duration("serve", "prefill_chunk",
@@ -596,11 +613,13 @@ class ServingEngine:
                 continue               # arena already holds the chunk
             # the first token is EOS-checked — a first-token EOS finishes
             # without any decode ticks instead of burning max_new - 1
-            tok = self.sampler.sample_one(
-                np.asarray(logits[r]), slot.request.sampling, step=slot.pos)
+            with xfa.scope("serve", "first_token"):
+                tok = self.sampler.sample_one(
+                    np.asarray(logits[r]), slot.request.sampling,
+                    step=slot.pos)
             self._emit(i, tok, time.monotonic())
 
-    @xfa.api("serve", "prefill_request")
+    @xfa.api("serve", "admit")
     def _admit(self, slot_idx: int, req: Request) -> int:
         """Bind `req` to slot `slot_idx` (truncation accounting, fresh
         batch=1 stash, sampler row) and return its first prefill chunk's
@@ -639,39 +658,55 @@ class ServingEngine:
         return self.scheduler.admit_cost(req)
 
     @xfa.api("serve", "decode_tick")
-    def _tick(self) -> int:
+    def _tick(self, stall_from: Optional[tuple] = None) -> int:
         """One pooled width-1 forward_chunk at per-slot positions over the
-        slots past prefill; returns #decoding."""
+        slots past prefill; returns #decoding.  `stall_from` is
+        (perf_counter_ns at step start, rows decoding then): the
+        `decode_stall` those rows saw before this call's dispatch."""
         slots = self.scheduler.slots
         active = self.scheduler.decoding()
         if not active:
             return 0
-        tokens = np.zeros((self.scfg.max_batch,), np.int32)
-        pos = self.scheduler.pos_vector()
-        for i in active:
-            tokens[i] = slots[i].request.output[-1]
-        if self.paged:
-            # the write frontier (row `pos`) may cross into a new page
+        with xfa.scope("serve", "decode_inputs"):
+            tokens = np.zeros((self.scfg.max_batch,), np.int32)
+            pos = self.scheduler.pos_vector()
             for i in active:
-                self._grant_rows(i, slots[i].pos + 1)
-        t0 = time.perf_counter_ns()
-        if self.paged:
-            logits, self.cache, self.table = self._decode(
-                self.params, jnp.asarray(tokens), self.table, self.cache,
-                jnp.asarray(pos), jnp.asarray(self.block_tables))
-        else:
-            logits, self.cache, self.table = self._decode(
-                self.params, jnp.asarray(tokens), self.table, self.cache,
-                jnp.asarray(pos))
-        nxt = self.sampler(logits, step=pos + 1)
+                tokens[i] = slots[i].request.output[-1]
+            if self.paged:
+                # the write frontier (row `pos`) may cross into a new page
+                for i in active:
+                    self._grant_rows(i, slots[i].pos + 1)
+                # pages the call's rows hold, against the block-table
+                # slots the paged kernel's grid addresses (max_batch x
+                # pages per row): the grid's live share is the ratio
+                xfa.record_gauge("serve", "decode_pages",
+                                 np.count_nonzero(self.block_tables[active]))
+                xfa.record_gauge("serve", "decode_page_slots",
+                                 self.block_tables.size)
+            t0 = time.perf_counter_ns()
+            args = (jnp.asarray(tokens), self.table, self.cache,
+                    jnp.asarray(pos))
+            if self.paged:
+                args += (jnp.asarray(self.block_tables),)
+        if stall_from is not None:
+            # step start to this dispatch, once per row that was waiting
+            # for it (weighted by tokens, as the inter-token gap is).
+            # Every prefill group of the tick synced before this point,
+            # so dispatch stands for device-ready here.
+            t_step, n = stall_from
+            xfa.record_duration("serve", "decode_stall",
+                                time.perf_counter_ns() - t_step, n=n)
+        logits, self.cache, self.table = self._decode(self.params, *args)
+        with xfa.scope("serve", "sample", kind=KIND_WAIT):
+            nxt = self.sampler(logits, step=pos + 1)
         tick_ns = time.perf_counter_ns() - t0
         now = time.monotonic()
-        for i in active:
-            slots[i].pos += 1
-            self._emit(i, int(nxt[i]), now)
-        if active:
-            xfa.record_duration("serve", "decode_token",
-                                tick_ns / len(active), n=len(active))
+        with xfa.scope("serve", "emit"):
+            for i in active:
+                slots[i].pos += 1
+                self._emit(i, int(nxt[i]), now)
+        xfa.record_duration("serve", "decode_token",
+                            tick_ns / len(active), n=len(active))
         return len(active)
 
     def _emit(self, slot_idx: int, tok: int, now: float) -> None:
@@ -683,6 +718,10 @@ class ServingEngine:
             req.first_token_at = now
             xfa.record_duration("serve", "ttft",
                                 (now - req.submitted_at) * 1e9)
+            # admission to first token: the request's time in prefill,
+            # its own chunks and the ticks it shared with others
+            xfa.record_duration("serve", "prefill_residence",
+                                (now - req.admitted_at) * 1e9)
         if req.on_token is not None:
             try:
                 req.on_token(req, tok)
@@ -717,8 +756,10 @@ class ServingEngine:
         synchronous (closed-loop) driver gets the same guarantee: an
         error marks the engine dead and wakes every waiter before the
         exception propagates to whoever drove the step."""
+        t_step = time.perf_counter_ns()
         with self._lock:
             try:
+                n_decoding = len(self.scheduler.decoding())
                 # queue depth at tick start, folded as a gauge: its
                 # per-interval mean across the snapshot ring is the
                 # saturation signal `diagnose` reads (a growing mean says
@@ -736,34 +777,37 @@ class ServingEngine:
                                      self.allocator.hwm)
                     xfa.record_gauge("serve", "cache_pages_capacity",
                                      self.allocator.usable)
-                cont, deferred = self.scheduler.continuation_plan()
-                # strict FCFS: if any mid-prefill slot (older than every
-                # waiting request) was deferred by the budget, nothing
-                # younger may spend the leftover this tick
-                picked = [] if deferred else self.scheduler.schedule(
-                    spent=sum(n for _, n in cont))
-                items = list(cont)
-                for k, (idx, req) in enumerate(picked):
-                    try:
-                        items.append((idx, self._admit(idx, req)))
-                    except Exception as e:
-                        # every request in `picked` was already popped
-                        # from the queue — none may vanish without waking
-                        # waiters: the failing one errors out, later ones
-                        # go back to the queue head (FCFS preserved) for
-                        # _fail_outstanding to find
-                        req.error = e
-                        req._done_event.set()
-                        self._release_pages(idx, req)
-                        self.scheduler.release(idx)
-                        for _, later in reversed(picked[k + 1:]):
-                            if self.paged:
-                                # the page gate reserved for them; back in
-                                # the queue they must not hold pages (they
-                                # re-reserve at their next gate pass)
-                                self.allocator.cancel(later.uid)
-                            self.scheduler.waiting.appendleft(later)
-                        raise
+                with xfa.scope("serve", "plan"):
+                    cont, deferred = self.scheduler.continuation_plan()
+                    # strict FCFS: if any mid-prefill slot (older than
+                    # every waiting request) was deferred by the budget,
+                    # nothing younger may spend the leftover this tick
+                    picked = [] if deferred else self.scheduler.schedule(
+                        spent=sum(n for _, n in cont))
+                    items = list(cont)
+                    for k, (idx, req) in enumerate(picked):
+                        try:
+                            items.append((idx, self._admit(idx, req)))
+                        except Exception as e:
+                            # every request in `picked` was already
+                            # popped from the queue — none may vanish
+                            # without waking waiters: the failing one
+                            # errors out, later ones go back to the queue
+                            # head (FCFS preserved) for _fail_outstanding
+                            # to find
+                            req.error = e
+                            req._done_event.set()
+                            self._release_pages(idx, req)
+                            self.scheduler.release(idx)
+                            for _, later in reversed(picked[k + 1:]):
+                                if self.paged:
+                                    # the page gate reserved for them;
+                                    # back in the queue they must not hold
+                                    # pages (they re-reserve at their next
+                                    # gate pass)
+                                    self.allocator.cancel(later.uid)
+                                self.scheduler.waiting.appendleft(later)
+                            raise
                 # continuations AND admissions batch together: one
                 # forward_chunk per same-width group of selected chunks
                 for idxs, ns, width in \
@@ -773,7 +817,7 @@ class ServingEngine:
                 # shared them by size, but holding them across ticks pins
                 # dead full-context rows for the engine's lifetime
                 self._pad_stashes.clear()
-                self._tick()
+                self._tick((t_step, n_decoding) if n_decoding else None)
                 self._ticks += 1
                 interval = self.scfg.profile_interval_ticks
                 if self._profile_store is not None and interval \

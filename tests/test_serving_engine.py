@@ -554,7 +554,7 @@ class TestEngineSemantics:
         folded = ProfileStore(run_dir).reduce().to_folded()
         apis = {k[2] for k in folded.edges}
         for phase in ("queue_wait", "ttft", "decode_token", "e2e",
-                      "prefill_request", "prefill_chunk", "decode_tick"):
+                      "admit", "prefill_chunk", "decode_tick"):
             assert phase in apis, f"missing serve phase {phase}"
         per_req = {k[2]: e for k, e in folded.edges.items()
                    if k[1] == "serve"}
