@@ -178,7 +178,12 @@ def update_cache_pages(arena: jax.Array, src: jax.Array, pos: jax.Array,
     Page 0 is the engine's reserved scratch page: bucket-pad rows and
     past-frontier writes of a padded chunk resolve there (their table
     entries are 0) and are overwritten or masked before any read — the
-    same discard contract dense pads have, made page-granular."""
+    same discard contract dense pads have, made page-granular.
+
+    Every axis up to the row axis is indexed (page, then each axis
+    between page and row, e.g. the GQA head axis, then row), so only the
+    axes after the row are the scatter's window: the write lands in the
+    arena's own layout and needs no re-laid-out copy of it."""
     ps = arena.shape[seq_axis]
     NB = block_table.shape[1]
     B = src.shape[0]
@@ -187,14 +192,31 @@ def update_cache_pages(arena: jax.Array, src: jax.Array, pos: jax.Array,
     blk = jnp.clip(abs_pos // ps, 0, NB - 1)
     pg = jnp.take_along_axis(jnp.asarray(block_table, jnp.int32), blk, axis=1)
     row = abs_pos % ps
-    # [B, ..., T, ...] -> [B*T, ...rest] matching the advanced-index
-    # selection shape (page and row indices broadcast to the front)
+    # [B, ..., T, ...] -> [B*T, mid..., tail...] matching the advanced-index
+    # selection shape: page and row ids [B*T, 1...] broadcast against an
+    # iota per mid axis to [B*T, mid...]; the tail is the window
+    mid = arena.shape[1:seq_axis]
     srcf = jnp.moveaxis(src, seq_axis, 1).reshape(
         (B * T,) + src.shape[1:seq_axis] + src.shape[seq_axis + 1:])
-    index = [slice(None)] * arena.ndim
-    index[0] = pg.reshape(-1)
-    index[seq_axis] = row.reshape(-1)
+    lead = (B * T,) + (1,) * len(mid)
+    index = [pg.reshape(lead)]
+    for i, n in enumerate(mid):
+        shape = [1] * len(lead)
+        shape[i + 1] = n
+        index.append(jnp.arange(n, dtype=jnp.int32).reshape(shape))
+    index.append(row.reshape(lead))
     return arena.at[tuple(index)].set(srcf.astype(arena.dtype))
+
+
+def layer_pages(arena: Params, block_table: jax.Array, layer: jax.Array
+                ) -> Tuple[Params, jax.Array]:
+    """This layer's view of the stacked page arena: every leaf [L, P, ...]
+    flattened to [L*P, ...] (a bitcast) and the block table offset to
+    layer `layer`'s pages, block_table + layer*P.  Page layer*P is the
+    layer's own scratch page, so the page-0 contract holds per layer."""
+    L, P = jax.tree.leaves(arena)[0].shape[:2]
+    flat = jax.tree.map(lambda a: a.reshape((L * P,) + a.shape[2:]), arena)
+    return flat, jnp.asarray(block_table, jnp.int32) + layer * P
 
 
 def last_valid(x: jax.Array, valid: Optional[jax.Array]) -> jax.Array:
@@ -228,7 +250,8 @@ def attention(p: Params, x: jax.Array, rt: Runtime, positions: jax.Array,
               cache: Optional[Params] = None, pos: Optional[jax.Array] = None,
               kv: Optional[jax.Array] = None, causal: bool = True,
               return_kv: bool = False,
-              block_table: Optional[jax.Array] = None
+              block_table: Optional[jax.Array] = None,
+              layer: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, Optional[Params]]:
     """GQA/MQA (optionally qk-norm) attention.
 
@@ -239,17 +262,20 @@ def attention(p: Params, x: jax.Array, rt: Runtime, positions: jax.Array,
     queries attend offset-causally against the row's full prefix.  S == 1
     is the pooled decode step, S > 1 an in-model prefill chunk — the same
     operation at different widths;
-    block_table: [B, NB] int32 page ids — when given, `cache` is a PAGE
-    ARENA ([P, Hkv, page_size, h] per layer) rather than per-row storage:
-    writes scatter and reads gather through the table, so a row only
-    touches the pages it was granted;
+    block_table: [B, NB] int32 page ids — when given, `cache` is the PAGE
+    ARENA of every layer ([L, P, Hkv, page_size, h]) rather than one
+    layer's per-row storage, and `layer` is this layer's index in it:
+    writes scatter and reads gather through the table offset to the
+    layer's pages (layer_pages), so a row only touches the pages it was
+    granted and the arena is updated in place;
     positions: [S] shared rope positions, or [B, S] per-row (chunk/decode);
     return_kv: return this call's post-rope K/V (prefill cache building).
     Returns (y [B, S, d], cache-or-kv).
     """
     if rt.cfg.mla:
         return mla_attention(p, x, rt, positions, cache, pos,
-                             return_kv=return_kv, block_table=block_table)
+                             return_kv=return_kv, block_table=block_table,
+                             layer=layer)
     cfg = rt.cfg
     ap = p["attn"]
     B, S, d = x.shape
@@ -282,24 +308,23 @@ def attention(p: Params, x: jax.Array, rt: Runtime, positions: jax.Array,
 
         if cache is not None and block_table is not None:
             # paged positioned chunk: scatter the S fresh rows through the
-            # block table into the shared page arena, read back the row's
-            # visible prefix through the same indirection
+            # layer's block table into the flat arena of every layer, read
+            # back the row's visible prefix through the same indirection
             pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-            ck = update_cache_pages(cache["k"], k, pos, block_table,
-                                    seq_axis=2)
-            cv = update_cache_pages(cache["v"], v, pos, block_table,
-                                    seq_axis=2)
+            flat, bt = layer_pages(cache, block_table, layer)
+            ck = update_cache_pages(flat["k"], k, pos, bt, seq_axis=2)
+            cv = update_cache_pages(flat["v"], v, pos, bt, seq_axis=2)
             if S == 1:                 # decode width: paged flash-decode
                 o = ops.decode_attention_paged(
-                    q[:, :, 0], ck, cv, block_table=block_table,
+                    q[:, :, 0], ck, cv, block_table=bt,
                     kv_len=pos + 1, impl=rt.impl)
                 o = o.reshape(B, 1, cfg.n_heads, h)
             else:                      # prefill chunk at per-row offsets
                 o = ops.chunk_attention_paged(
-                    q, ck, cv, block_table=block_table, pos=pos,
-                    impl=rt.impl)
+                    q, ck, cv, block_table=bt, pos=pos, impl=rt.impl)
                 o = o.swapaxes(1, 2)                   # [B,S,Hq,h]
-            new_cache = {"k": ck, "v": cv}
+            new_cache = {"k": ck.reshape(cache["k"].shape),
+                         "v": cv.reshape(cache["v"].shape)}
         elif cache is not None:
             # positioned chunk: append each row's S fresh k/v rows at its
             # own `pos`, attend to the row's own prefix (offset-causal)
@@ -331,7 +356,8 @@ def mla_attention(p: Params, x: jax.Array, rt: Runtime, positions: jax.Array,
                   cache: Optional[Params] = None,
                   pos: Optional[jax.Array] = None,
                   return_kv: bool = False,
-                  block_table: Optional[jax.Array] = None
+                  block_table: Optional[jax.Array] = None,
+                  layer: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, Optional[Params]]:
     """Multi-head Latent Attention (DeepSeek-V2).
 
@@ -339,9 +365,9 @@ def mla_attention(p: Params, x: jax.Array, rt: Runtime, positions: jax.Array,
     Decode: matrix-absorbed latent attention — the cache stores ONLY
     (c_kv [B,S,r], k_rope [B,S,dr]); queries are projected into the latent
     space, and the decode kernel runs with a single latent 'kv head'.
-    With block_table the latent cache is a page arena ([P, page_size, r] /
-    [P, page_size, dr]) addressed exactly like the GQA one — the latent
-    rows page the same way full K/V rows do."""
+    With block_table the latent cache is the page arena of every layer
+    ([L, P, page_size, r] / [L, P, page_size, dr]) addressed exactly like
+    the GQA one — the latent rows page the same way full K/V rows do."""
     cfg = rt.cfg
     ap = p["attn"]
     B, S, d = x.shape
@@ -371,14 +397,22 @@ def mla_attention(p: Params, x: jax.Array, rt: Runtime, positions: jax.Array,
             # kernel (S > 1) over the single latent 'kv head'
             pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
             if block_table is not None:
-                cc = update_cache_pages(cache["ckv"], c_kv, pos,
-                                        block_table, seq_axis=1)
-                cr = update_cache_pages(cache["krope"], k_rope[:, 0], pos,
-                                        block_table, seq_axis=1)
+                flat, bt = layer_pages(cache, block_table, layer)
+                new_cache = {
+                    "ckv": update_cache_pages(flat["ckv"], c_kv, pos, bt,
+                                              seq_axis=1),
+                    "krope": update_cache_pages(flat["krope"], k_rope[:, 0],
+                                                pos, bt, seq_axis=1)}
+                new_cache = jax.tree.map(lambda f, a: f.reshape(a.shape),
+                                         new_cache, cache)
+                # the kernel operands below hold this layer's pages only,
+                # read through the layer's own (un-offset) block table
+                cc, cr = new_cache["ckv"][layer], new_cache["krope"][layer]
             else:
                 cc = update_cache_rows(cache["ckv"], c_kv, pos, seq_axis=1)
                 cr = update_cache_rows(cache["krope"], k_rope[:, 0], pos,
                                        seq_axis=1)
+                new_cache = {"ckv": cc, "krope": cr}
             # absorb: q_latent = q_nope @ wk_b^T  -> [B,nh,S,r]
             q_lat = jnp.einsum("bhtd,rhd->bhtr",
                                q_nope.swapaxes(1, 2).astype(jnp.float32),
@@ -413,7 +447,6 @@ def mla_attention(p: Params, x: jax.Array, rt: Runtime, positions: jax.Array,
             o_lat = o_lat[..., :r]
             o = jnp.einsum("bthr,rhd->bthd", o_lat.astype(jnp.float32),
                            wv_b.astype(jnp.float32)).astype(x.dtype)
-            new_cache = {"ckv": cc, "krope": cr}
         else:
             from repro.parallel.axes import shard_dims
             _ch = lambda t: shard_dims(t, {0: "batch", 1: "model"})

@@ -8,10 +8,12 @@ Structure decisions that matter at scale:
     data-dependent metrics into it.
   * trace-time static costs use core.device_fold.scan_multiplier so one
     traced body registers L layers' worth of analytic FLOPs.
-  * KV caches are stacked [L, ...] pytrees scanned together with the params;
-    prefill and decode share ONE positioned-chunk body (forward_chunk) — a
-    chunk of T tokens lands at per-row cache offsets, T = 1 being the pooled
-    decode tick and pos = 0, T = S being bulk prefill.
+  * KV caches are stacked [L, ...] pytrees scanned together with the params
+    (the paged arena instead rides whole in the scan carry and is updated
+    in place); prefill and decode share ONE positioned-chunk body
+    (forward_chunk) — a chunk of T tokens lands at per-row cache offsets,
+    T = 1 being the pooled decode tick and pos = 0, T = S being bulk
+    prefill.
 """
 
 from __future__ import annotations
@@ -49,11 +51,14 @@ def decoder_layer(p: Params, x: jax.Array, rt: Runtime, table: jax.Array,
                   cache: Optional[Params] = None,
                   pos: Optional[jax.Array] = None,
                   return_kv: bool = False,
-                  block_table: Optional[jax.Array] = None):
-    """Pre-norm block. Returns (x, table, aux, new_cache)."""
+                  block_table: Optional[jax.Array] = None,
+                  layer: Optional[jax.Array] = None):
+    """Pre-norm block. Returns (x, table, aux, new_cache).  With a
+    block_table, `cache` is the page arena of every layer and `layer`
+    this block's index in it."""
     h = norm(p["norm1"], x, rt)
     a, new_cache = attention(p, h, rt, positions, cache=cache, pos=pos,
-                             block_table=block_table)
+                             block_table=block_table, layer=layer)
     x = x + a
     h = norm(p["norm2"], x, rt)
     if kind == "moe":
@@ -191,7 +196,8 @@ def forward_chunk(p: Params, tokens: jax.Array, rt: Runtime, table: jax.Array,
     frontier but the NEXT chunk overwrites them and no query ever attends
     them).  block_table: [B, NB] int32 — when given, `cache` is the paged
     arena ([L, P, Hkv, page_size, h]) and every layer writes/reads through
-    the SAME per-slot table (one table per slot, shared across layers).
+    the SAME per-slot table (one table per slot, shared across layers),
+    offset to its own pages (layer l's page v is l*P + v).
     Returns (last-valid-token logits [B, V], new stacked cache, table).
 
     Prefill and decode are this operation at different widths: pos = 0,
@@ -200,36 +206,72 @@ def forward_chunk(p: Params, tokens: jax.Array, rt: Runtime, table: jax.Array,
     advances independently — rope angles, row-range cache scatters and
     offset-causal masks are all per-row — so one compiled call serves
     slots at arbitrary mixed depths."""
-    cfg = rt.cfg
     x = embed(p, tokens, rt)
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
     B, T = x.shape[:2]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos[:, None] + jnp.arange(T)[None, :]   # [B, T] per-row rope
-    counts = [c for _, c in _layer_kinds(cfg)]
-    cache_segs = _split_cache(cache, counts)
+    if block_table is not None:
+        x, table, new_cache = _paged_layers(p, x, rt, table, cache, positions,
+                                            pos, block_table)
+    else:
+        x, table, new_cache = _dense_layers(p, x, rt, table, cache, positions,
+                                            pos)
+    x = norm(p["final_norm"], x, rt)
+    logits = lm_head(p, last_valid(x, valid), rt)[:, 0]
+    return logits, new_cache, table
 
+
+def _dense_layers(p: Params, x: jax.Array, rt: Runtime, table: jax.Array,
+                  cache: Params, positions: jax.Array, pos: jax.Array):
+    """Per-row caches: each stack scans its [count, ...] cache segment
+    with its params and restacks the layers' new caches."""
     new_segs = []
-    for (kind, count, stack), seg in zip(_stacks(p, cfg), cache_segs):
+    counts = [c for _, c in _layer_kinds(rt.cfg)]
+    for (kind, count, stack), seg in zip(_stacks(p, rt.cfg),
+                                         _split_cache(cache, counts)):
         def body(carry, inp, kind=kind):
             x, table = carry
             layer_p, layer_cache = inp
             x, table, _, new_cache = decoder_layer(
                 layer_p, x, rt, table, positions, kind,
-                cache=layer_cache, pos=pos, block_table=block_table)
+                cache=layer_cache, pos=pos)
             return (x, table), new_cache
 
         with scan_multiplier(count):
             (x, table), new_seg = jax.lax.scan(body, (x, table), (stack, seg))
         new_segs.append(new_seg)
-
-    x = norm(p["final_norm"], x, rt)
-    logits = lm_head(p, last_valid(x, valid), rt)[:, 0]
     new_cache = jax.tree.map(
         lambda *segs: jnp.concatenate(segs, 0), *new_segs) \
         if len(new_segs) > 1 else new_segs[0]
-    return logits, new_cache, table
+    return x, table, new_cache
+
+
+def _paged_layers(p: Params, x: jax.Array, rt: Runtime, table: jax.Array,
+                  arena: Params, positions: jax.Array, pos: jax.Array,
+                  block_table: jax.Array):
+    """The page arena of every layer rides whole in the scan carry; each
+    stack scans (params, layer index) from its own first layer, and
+    layer l writes and reads its pages at block_table + l*P of the flat
+    arena (layers.layer_pages).  No layer is sliced out of the arena or
+    restacked, so the scatter updates the donated arena in place."""
+    first = 0
+    for kind, count, stack in _stacks(p, rt.cfg):
+        def body(carry, inp, kind=kind):
+            x, table, arena = carry
+            layer_p, layer = inp
+            x, table, _, arena = decoder_layer(
+                layer_p, x, rt, table, positions, kind, cache=arena,
+                pos=pos, block_table=block_table, layer=layer)
+            return (x, table, arena), None
+
+        layers = first + jnp.arange(count, dtype=jnp.int32)
+        with scan_multiplier(count):
+            (x, table, arena), _ = jax.lax.scan(body, (x, table, arena),
+                                                (stack, layers))
+        first += count
+    return x, table, arena
 
 
 def prefill(p: Params, tokens: jax.Array, rt: Runtime, table: jax.Array,
@@ -254,7 +296,8 @@ def init_paged_cache(cfg: ModelConfig, pages: int, page_size: int, dtype=None
     Ownership lives outside: the engine's block tables map (slot,
     virtual page) -> arena page, so a 30-token slot holds one page and a
     full-context one holds max_seq_len / page_size — memory follows the
-    request, not the worst case.  Page 0 is reserved scratch."""
+    request, not the worst case.  Page 0 is reserved scratch (in every
+    layer: layer l's page v is page l*P + v of the flat arena)."""
     return init_cache(cfg, pages, page_size, dtype)
 
 

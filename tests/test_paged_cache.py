@@ -29,13 +29,26 @@ import pytest
 
 from repro.configs.base import ServeConfig
 from repro.kernels import ops, ref
-from repro.models import layers
+from repro.models import build_model, layers
 from repro.serving import PageAllocator, ServingEngine
 from test_serving_engine import (SERVING_ARCHS, build, mixed_prompts,
-                                 sequential_decode)
+                                 sequential_decode, tiny)
 
 PAGED_ARCHS = ["tinyllama_1_1b", "deepseek_v2_lite_16b"]   # attention KV
 DENSE_ARCHS = ["zamba2_2_7b", "xlstm_1_3b"]                # recurrent state
+# GQA MoE given a dense first layer: two layer stacks, so the MoE stack's
+# scan addresses the arena from layer offset 1
+MULTI_STACK_MOE = "phi3_5_moe_42b"
+
+
+def build_paged(arch, seed=0):
+    """`build`, except that MULTI_STACK_MOE gets 3 layers in two stacks."""
+    if arch != MULTI_STACK_MOE:
+        return build(arch, seed)
+    cfg = dataclasses.replace(tiny(arch), n_layers=3, first_dense_layers=1,
+                              d_ff=256).validate()
+    model = build_model(cfg, impl="ref")
+    return cfg, model, model.init(jax.random.key(seed))
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -259,6 +272,42 @@ class TestPagedAttentionKernels:
                                        atol=2e-5)
 
 
+class TestArenaWriteIsolation:
+    PAGES, PS = 12, 4
+
+    @pytest.mark.parametrize("arch", PAGED_ARCHS)
+    def test_layers_write_only_granted_pages(self, arch):
+        """The arena of every layer rides whole through the layer scan:
+        after a chunk call and a decode call, each layer has changed only
+        the pages the block table granted and its own scratch page, and
+        every other page of every layer is bit-identical."""
+        cfg, model, params = build(arch)
+        zeros = model.init_paged_cache(self.PAGES, self.PS)
+        keys = jax.random.split(jax.random.key(3), len(zeros))
+        before = {name: jax.random.normal(k, a.shape, a.dtype)
+                  for k, (name, a) in zip(keys, sorted(zeros.items()))}
+        # row 0 writes virtual rows 0..6 (pages 3, 5), row 1 rows 3..9
+        # (pages 7, 2, 9); entries past the frontier stay on scratch
+        bt = jnp.asarray([[3, 5, 0, 0], [7, 2, 9, 0]], jnp.int32)
+        tokens = jnp.asarray(mixed_prompts(cfg, lengths=(6, 6)))
+        _, arena, table = model.forward_chunk_paged(
+            params, tokens, model.table(), before,
+            jnp.asarray([0, 3], jnp.int32),
+            bt, jnp.asarray([6, 6], jnp.int32))
+        _, arena, _ = model.decode_step_paged(
+            params, tokens[:, 0], table, arena,
+            jnp.asarray([6, 9], jnp.int32), bt)
+        granted = {2, 3, 5, 7, 9}
+        for name in before:
+            old, new = np.asarray(before[name]), np.asarray(arena[name])
+            assert new.shape[:2] == (cfg.n_layers, self.PAGES)
+            for layer in range(cfg.n_layers):
+                changed = {pg for pg in range(self.PAGES)
+                           if not np.array_equal(old[layer, pg],
+                                                 new[layer, pg])}
+                assert changed - {0} == granted, (name, layer, changed)
+
+
 class TestPageAllocator:
     def test_reserve_grant_release_accounting(self):
         a = PageAllocator(9, 4)          # 8 usable (page 0 scratch)
@@ -299,10 +348,10 @@ class TestPagedEngineEquivalence:
     @pytest.mark.parametrize("arch,chunk", [
         *[(a, 64) for a in PAGED_ARCHS],
         ("tinyllama_1_1b", 1), ("tinyllama_1_1b", 3),
-        ("deepseek_v2_lite_16b", 3),
+        ("deepseek_v2_lite_16b", 3), (MULTI_STACK_MOE, 3),
     ])
     def test_paged_matches_contiguous_and_sequential(self, arch, chunk):
-        cfg, model, params = build(arch)
+        cfg, model, params = build_paged(arch)
         prompts = mixed_prompts(cfg)
         max_new = [6, 5, 6, 4]
 

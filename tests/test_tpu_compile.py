@@ -15,14 +15,20 @@ import: only one process may load the TPU library at a time, and every
 test worker imports every test file.
 """
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import tinyllama_1_1b
 from repro.kernels import decode_attention as dec
 from repro.kernels import flash_attention as fa
+from repro.kernels import ops
 from repro.kernels import rmsnorm as rms
+from repro.models import build_model
 
 # TinyLlama-1.1B widths (configs/tinyllama_1_1b.py)
 B, HQ, HKV, D, D_MODEL = 4, 32, 4, 64, 2048
@@ -124,3 +130,62 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for shape, dtype in arg_specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+# `%name = bf16[d0,d1,...]{layout} opcode(` in the compiled program's text
+HLO_OP = re.compile(r"= (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+# Eight rows of the full context: an arena past the chip's 128 MiB of
+# VMEM, so a copy of it would count among the program's temporaries (a
+# smaller arena's copies can sit in VMEM, which temp bytes leave out)
+ARENA_PAGES = 8 * CACHE // PAGE + 1
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_paged_program_updates_arena_in_place(one_chip, monkeypatch,
+                                              program):
+    """The whole paged decode and prefill-chunk programs of a 4-layer
+    model: the arena rides through the layer scan and the KV write lands
+    in it in place, so the program needs less scratch than one layer's k
+    slice and copies no layer of the arena (nor the whole of it).
+
+    TinyLlama's widths with head_dim 128 (16 q heads, so d_model stays
+    2048): the chip's default layout for an array whose minor dim is 64
+    puts another dim minor, so a 64-wide arena is re-laid-out on entry
+    and exit of every call, whatever the layer scan does."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # lower Mosaic
+    L = 4
+    cfg = dataclasses.replace(tinyllama_1_1b.CONFIG, n_layers=L,
+                              n_heads=HQ // 2, head_dim=2 * D,
+                              param_dtype="bfloat16",
+                              compute_dtype="bfloat16")
+    model = build_model(cfg)
+    put = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = put(jax.eval_shape(model.init, jax.random.key(0)))
+    table = put(jax.eval_shape(model.table))
+    arena = put(jax.eval_shape(
+        lambda: model.init_paged_cache(ARENA_PAGES, PAGE)))
+    i32s = lambda *shape: jax.ShapeDtypeStruct(shape, i32, sharding=one_chip)
+    nb = CACHE // PAGE
+    if program == "decode":
+        compiled = jax.jit(model.decode_step_paged, donate_argnums=(3,)).lower(
+            params, i32s(B), table, arena, i32s(B), i32s(B, nb)).compile()
+    else:                       # one row, one page of prompt
+        compiled = jax.jit(model.forward_chunk_paged,
+                           donate_argnums=(3,)).lower(
+            params, i32s(1, PAGE), table, arena, i32s(1), i32s(1, nb),
+            i32s(1)).compile()
+
+    k_slice = arena["k"].size // L * arena["k"].dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    arena_sizes = {arena["k"].size // L, arena["k"].size}
+    copies = []
+    for dtype, dims, op in HLO_OP.findall(compiled.as_text()):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        if op in ("copy", "dynamic-update-slice") and n in arena_sizes:
+            copies.append(f"{op} {dtype}[{dims}]")
+    assert temp < k_slice, (temp, k_slice)
+    assert not copies, copies
