@@ -33,7 +33,7 @@ def _leaf_norms(tree, shapes) -> Dict[str, float]:
         for path, x in jax.tree_util.tree_flatten_with_path(t)[0]:
             x = x.astype(jnp.float32)
             axes = tuple(range(1, x.ndim))
-            if weights._path(path).startswith(weights.STACK):
+            if weights.stacked_name(path) is not None:
                 out.append(jnp.sqrt(jnp.sum(x * x, axis=axes)))
             else:
                 out.append(jnp.sqrt(jnp.sum(x * x))[None])

@@ -1,14 +1,19 @@
 """The yardstick's arithmetic: percentiles, spreads, interval unions,
 FLOP and byte counts, traffic steadiness, weights, configurations."""
 
+import copy
+import json
 import math
 import statistics
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from bench import flops, stats, traffic
 from bench.spec import Spec, reader
+
+DATA = Path(__file__).resolve().parent / "data"
 
 
 def test_percentile_nearest_rank():
@@ -86,6 +91,107 @@ def test_train_flops_per_token():
     assert flops.train_flops_per_token(DIMS, 8) == pytest.approx(want)
 
 
+# Readings of the counts the dense configurations were measured with,
+# taken before MoE and MLA were counted: they may not move.
+GOLDEN_ROWS = [(0, 512), (100, 1), (3000, 1), (512, 128), (7, 0), (4095, 1)]
+GOLDEN_KV = [1, 513, 4095, 64]
+GOLDEN = {
+    "starcoder2-7b": {
+        "serve": 4531008503808.0, "train": 23102668800.0,
+        "decode": (86132736.0, 9644032.0), "chunk": (3913445376.0, 28952576.0),
+        "flash": (309388640256.0, 337903616)},
+    "granite-20b": {
+        "serve": 6407981776896.0, "train": 32360583168.0,
+        "decode": (114843648.0, 2490880.0), "chunk": (5217927168.0, 20077568.0),
+        "flash": (412518187008.0, 414187520)},
+    "starcoder2-7b-train": {
+        "serve": 566659178496.0, "train": 3057702912.0,
+        "decode": (86132736.0, 9644032.0), "chunk": (3913445376.0, 28952576.0),
+        "flash": (309388640256.0, 337903616)},
+}
+
+
+@pytest.mark.parametrize("config", sorted(GOLDEN))
+def test_dense_config_counts_unchanged(config):
+    dims = flops.Dims.of(Spec.load().config(config)["program"])
+    assert {"serve": flops.serve_step_flops(dims, GOLDEN_ROWS),
+            "train": flops.train_flops_per_token(dims, 2048),
+            "decode": flops.decode_attn_cost(dims, GOLDEN_KV),
+            "chunk": flops.chunk_attn_cost(dims, GOLDEN_ROWS),
+            "flash": flops.flash_attn_cost(dims, 8, 2048)} == GOLDEN[config]
+
+
+# one dense layer, then two MoE layers of 4 experts (top 2, 1 shared) of
+# width 6; MLA with r 4, dn 2, dr 2, dv 3 over 2 heads
+MLA_MOE = flops.Dims(layers=3, d_model=8, heads=2, kv_heads=2, head_dim=4,
+                     d_ff=16, vocab=10, gated=True, n_experts=4, top_k=2,
+                     n_shared_experts=1, moe_d_ff=6, first_dense_layers=1,
+                     mla=True, kv_lora_rank=4, qk_nope_dim=2, qk_rope_dim=2,
+                     v_head_dim=3)
+
+
+def test_moe_mla_params_by_hand():
+    # q 8x(2*4), kv down 8x(4+2), kv up 4x(2*(2+3)), o (2*3)x8
+    attn = 64 + 48 + 40 + 48
+    assert MLA_MOE.attn_params == attn
+    assert MLA_MOE.layer_matmul_params == attn + 3 * 8 * 16
+    # router 8x4; 2 routed + 1 shared gated experts of 3 x 8x6
+    assert MLA_MOE.moe_layer_params == attn + 32 + 3 * 144
+    assert MLA_MOE.matmul_params == (attn + 384) + 2 * (attn + 32 + 432)
+
+
+def test_moe_mla_serve_step_flops_by_hand():
+    # latent form: 2 heads score over r + dr = 6, sum values over r = 4
+    pair = 2 * 2 * 6 + 2 * 2 * 4
+    want = 0.0
+    for n, ctx in ((3, 1 + 2 + 3), (1, 8)):
+        want += 2 * n * 1912 + 2 * 8 * 10 + 3 * pair * ctx
+    assert flops.serve_step_flops(MLA_MOE, [(0, 3), (7, 1), (4, 0)]) == want
+
+
+def test_moe_mla_train_flops_by_hand():
+    # expanded form: 2 heads score over dn + dr = 4, sum values over dv = 3
+    pair = 2 * 2 * 4 + 2 * 2 * 3
+    want = 3 * (2 * (1912 + 8 * 10) + 3 * pair * (8 + 1) / 2)
+    assert flops.train_flops_per_token(MLA_MOE, 8) == want
+
+
+def test_mla_kernel_costs_by_hand():
+    # one latent row of 6 per position; q 2x6 and o 2x4 in latent space
+    fl, by = flops.decode_attn_cost(MLA_MOE, [3, 5])
+    assert fl == 40 * 8
+    assert by == 8 * 6 * 2 + 2 * (2 * (6 + 4) * 2)
+    fl, by = flops.chunk_attn_cost(MLA_MOE, [(2, 2)])
+    assert fl == 40 * (3 + 4)
+    assert by == 4 * 6 * 2 + 2 * 2 * (6 + 4) * 2
+    # expanded heads: q, k of 2x4 and v, o of 2x3 per position
+    fl, by = flops.flash_attn_cost(MLA_MOE, 1, 4)
+    assert fl == 28 * 10
+    assert by == 4 * 2 * (2 * 4 + 2 * 4 + 2 * 3 + 2 * 3) + 2 * 4 * 4
+
+
+def test_expert_ffn_cost_by_hand():
+    # an expert with no tokens reads no weights
+    fl, by = flops.expert_ffn_cost(MLA_MOE, [3, 0, 1, 2])
+    assert fl == (3 + 1 + 2) * 6 * 8 * 6
+    assert by == 3 * (3 * 8 * 6 * 2) + (3 + 1 + 2) * 2 * 8 * 2
+    assert flops.expert_ffn_cost(MLA_MOE, [0, 0]) == (0.0, 0.0)
+
+
+def test_dims_of_reads_the_program_fields():
+    program = json.loads((DATA / "configs" / "deepseek-v2-lite.json")
+                         .read_text())["program"]
+    dims = flops.Dims.of(program)
+    assert dims.gated and dims.mla and dims.moe_layers == 5
+    # MLA 13.8M, router 0.13M, 8 experts of 3 x 2048x1408: 83.1M
+    assert dims.attn_params == 13_762_560
+    assert dims.moe_layer_params == 13_762_560 + 131_072 + 69_206_016
+    dense = flops.Dims.of({**program, "moe": False, "mla": False,
+                           "mlp_gated": False})
+    assert (dense.moe_layers, dense.head_dim) == (0, 128)
+    assert dense.matmul_params == 6 * (2048 * 128 * 64 + 2 * 2048 * 10944)
+
+
 MIX = {"rate_per_s": 5.0, "drain_s": 4, "base_seed": 7,
        "prompt": {"median": 50, "sigma": 0.8, "min": 8, "max": 200},
        "output": {"median": 20, "sigma": 0.8, "min": 4, "max": 64}}
@@ -139,24 +245,64 @@ def test_weights_by_layer_equal_the_stacked_call():
                 == np.asarray(p["embed"]["table"])).all()
 
 
+# by model_type: program field <- the published config.json key, or a
+# function of the file
+PUBLISHED = {
+    "starcoder2": {
+        "d_model": "hidden_size", "n_heads": "num_attention_heads",
+        "n_kv_heads": "num_key_value_heads", "d_ff": "intermediate_size",
+        "n_layers": "num_hidden_layers", "vocab": "vocab_size"},
+    "gpt_bigcode": {
+        "d_model": "n_embd", "n_heads": "n_head",
+        "n_kv_heads": lambda c: 1 if c["multi_query"] else c["n_head"],
+        "d_ff": "n_inner", "n_layers": "n_layer", "vocab": "vocab_size"},
+    "deepseek_v2": {
+        "d_model": "hidden_size", "n_heads": "num_attention_heads",
+        "n_kv_heads": "num_key_value_heads", "d_ff": "intermediate_size",
+        "moe_d_ff": "moe_intermediate_size", "n_experts": "n_routed_experts",
+        "top_k": "num_experts_per_tok", "n_shared_experts": "n_shared_experts",
+        "first_dense_layers": "first_k_dense_replace",
+        "kv_lora_rank": "kv_lora_rank", "qk_nope_dim": "qk_nope_head_dim",
+        "qk_rope_dim": "qk_rope_head_dim", "v_head_dim": "v_head_dim",
+        "n_layers": "num_hidden_layers", "vocab": "vocab_size",
+        "moe": lambda c: True, "mla": lambda c: True},
+}
+
+
+def agree_with_published(cfg: dict) -> None:
+    kind = cfg.get("model_type")
+    assert kind in PUBLISHED, f"no published keys known for model_type {kind!r}"
+    p = cfg["program"]
+    for field, key in PUBLISHED[kind].items():
+        want = key(cfg) if callable(key) else cfg[key]
+        assert p[field] == want, (kind, field, p[field], want)
+    if not p.get("mla"):
+        assert p["head_dim"] * p["n_heads"] == p["d_model"]
+    assert set(cfg["published"]) == set(cfg["reduced"])
+
+
 def test_configs_agree_with_their_published_keys():
     spec = Spec.load()
     for c in spec.data["configs"]:
         cfg = spec.config(c["name"])
-        p = cfg["program"]
-        if cfg["model_type"] == "starcoder2":
-            pub = (cfg["hidden_size"], cfg["num_attention_heads"],
-                   cfg["num_key_value_heads"], cfg["intermediate_size"],
-                   cfg["num_hidden_layers"], cfg["vocab_size"])
-        else:
-            pub = (cfg["n_embd"], cfg["n_head"],
-                   1 if cfg["multi_query"] else cfg["n_head"],
-                   cfg["n_inner"], cfg["n_layer"], cfg["vocab_size"])
-        assert pub == (p["d_model"], p["n_heads"], p["n_kv_heads"],
-                       p["d_ff"], p["n_layers"], p["vocab"])
-        assert p["head_dim"] * p["n_heads"] == p["d_model"]
+        agree_with_published(cfg)
         assert sorted(c["reduced"]) == sorted(cfg["reduced"])
-        assert set(cfg["published"]) == set(cfg["reduced"])
+    agree_with_published(json.loads(
+        (DATA / "configs" / "deepseek-v2-lite.json").read_text()))
+
+
+def test_published_keys_catch_each_field():
+    cfg = json.loads((DATA / "configs" / "deepseek-v2-lite.json").read_text())
+    for field in PUBLISHED["deepseek_v2"]:
+        bad = copy.deepcopy(cfg)
+        bad["program"][field] = not bad["program"][field] \
+            if isinstance(bad["program"][field], bool) \
+            else bad["program"][field] + 1
+        with pytest.raises(AssertionError, match=field):
+            agree_with_published(bad)
+    cfg["model_type"] = "xlstm"
+    with pytest.raises(AssertionError, match="'xlstm'"):
+        agree_with_published(cfg)
 
 
 def test_every_named_file_exists():
